@@ -1,5 +1,6 @@
-"""Architecture config registry: the 10 assigned architectures (+ the
-paper-scale spec-dec pair) selectable via ``--arch <id>``."""
+"""Architecture config registry: the assigned architectures plus the
+SmolLM drafter (+ the paper-scale spec-dec pair) selectable via
+``--arch <id>``."""
 
 from __future__ import annotations
 
@@ -24,6 +25,7 @@ _MODULES = {
     "llama3-405b": "llama3_405b",
     "mixtral-8x22b": "mixtral_8x22b",
     "smollm-360m": "smollm_360m",
+    "smollm-135m": "smollm_135m",
     "recurrentgemma-2b": "recurrentgemma_2b",
     "granite-34b": "granite_34b",
 }

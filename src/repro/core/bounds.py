@@ -106,8 +106,10 @@ def iid_draft_acceptance_upper(p: jax.Array, q: jax.Array, k: int) -> jax.Array:
 
     (the list contains symbol j with probability 1-(1-p_j)^K; a coupling
     cannot beat the pointwise min). Used as the Fig.-6 reference curve.
+    Clipped at 1: it is a probability, and the float32 sum of a
+    normalized ``q`` can land one ulp above 1.
     """
-    return jnp.sum(jnp.minimum(q, 1.0 - (1.0 - p) ** k))
+    return jnp.minimum(jnp.sum(jnp.minimum(q, 1.0 - (1.0 - p) ** k)), 1.0)
 
 
 def wz_error_upper_bound(info_density: jax.Array, k: int, l_max: int) -> jax.Array:

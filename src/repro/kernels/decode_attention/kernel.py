@@ -55,7 +55,7 @@ def _kernel(kv_len_ref, q_ref, k_ref, v_ref, *refs,
         v = v * v_s_ref[0, 0]
     d = q.shape[-1]
     g = q.shape[0]
-    kv_len = kv_len_ref[0]
+    kv_len = kv_len_ref[pl.program_id(0)]
 
     s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) / jnp.sqrt(
         jnp.float32(d))                        # (G, TK)
@@ -112,8 +112,10 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 
     kernel = functools.partial(_kernel, tk=tk, n_kv=n_kv, quant=quant)
     in_specs = [
-        pl.BlockSpec((1,), lambda b_, h_, ik: (b_,),
-                     memory_space=pltpu.SMEM),
+        # The whole (B,) length vector sits in SMEM: a (1,) block of a
+        # rank-1 array is not a legal TPU tile (it must equal the array
+        # or be a multiple of 128), so each program reads its own entry.
+        pl.BlockSpec(memory_space=pltpu.SMEM),
         pl.BlockSpec((1, 1, g, d), lambda b_, h_, ik: (b_, h_, 0, 0)),
         pl.BlockSpec((1, 1, tk, d), lambda b_, h_, ik: (b_, h_, ik, 0)),
         pl.BlockSpec((1, 1, tk, d), lambda b_, h_, ik: (b_, h_, ik, 0)),

@@ -61,8 +61,8 @@ def _kernel(q_off_ref, kv_len_ref, q_ref, k_ref, v_ref, *refs,
         k = k * k_s_ref[0, 0]                # (TK, 1) broadcasts over D
         v = v * v_s_ref[0, 0]
     d = q.shape[-1]
-    q_off = q_off_ref[0]
-    kv_len = kv_len_ref[0]
+    q_off = q_off_ref[pl.program_id(0)]
+    kv_len = kv_len_ref[pl.program_id(0)]
 
     s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) / jnp.sqrt(
         jnp.float32(d))
@@ -151,10 +151,12 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                                causal=causal, window=window, t_real=t,
                                quant=quant)
     in_specs = [
-        pl.BlockSpec((1,), lambda b_, h_, iq, ik: (b_,),
-                     memory_space=pltpu.SMEM),
-        pl.BlockSpec((1,), lambda b_, h_, iq, ik: (b_,),
-                     memory_space=pltpu.SMEM),
+        # Whole (B,) offset/length vectors in SMEM, indexed by the
+        # batch program id: a (1,) block of a rank-1 array is not a
+        # legal TPU tile (it must equal the array or be a multiple of
+        # 128).
+        pl.BlockSpec(memory_space=pltpu.SMEM),
+        pl.BlockSpec(memory_space=pltpu.SMEM),
         pl.BlockSpec((1, 1, tq, d), lambda b_, h_, iq, ik: (b_, h_, iq, 0)),
         pl.BlockSpec((1, 1, tk, d),
                      lambda b_, h_, iq, ik, g=g: (b_, h_ // g, ik, 0)),

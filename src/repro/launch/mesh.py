@@ -2,36 +2,25 @@
 
 A FUNCTION, not a module-level constant, so importing this module never
 touches jax device state (per the dry-run contract).
-
-``compat_make_mesh`` papers over the jax.sharding.AxisType API (added in
-newer JAX): on versions without it, ``axis_types`` is simply omitted —
-meshes default to Auto axes there, so semantics are unchanged.
 """
 
 from __future__ import annotations
 
 import jax
-
-
-def compat_make_mesh(shape: tuple, axes: tuple):
-    """jax.make_mesh with Auto axis types where the API supports them."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips per pod; (2,16,16) = 512 chips across 2 pods."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat_make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh():
     """Single-device mesh for CPU smoke runs (tests/examples)."""
-    return compat_make_mesh((1, 1), ("data", "model"))
+    return jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def make_tp_mesh(tp: int):
@@ -50,4 +39,4 @@ def make_tp_mesh(tp: int):
             f"has {jax.device_count()} (CPU tests: set XLA_FLAGS="
             f"--xla_force_host_platform_device_count=8 before importing "
             "jax)")
-    return compat_make_mesh((tp,), ("model",))
+    return jax.make_mesh((tp,), ("model",), axis_types=(AxisType.Auto,))

@@ -23,19 +23,45 @@ bit-identically.  ``--deadline-ms/--max-queue/--rate-limit`` arm the
 overload valves and ``--heal-after`` the degradation-ladder circuit
 breaker.
 
-Loads checkpoints if given, otherwise trains a small pair on the
-synthetic corpus first (CPU-scale demonstration of the full path)."""
+``--target ARCH --drafter ARCH`` serves two architectures of
+``repro.configs`` at their published widths in float32, with random
+weights drawn from ``--init-seed`` (under ``--tp N`` each weight is
+created already sharded) and seeded random prompts of 16-128 tokens:
+
+  python -m repro.launch.serve --target smollm-360m \
+      --drafter smollm-135m --cache-mode kv_fused --backend pallas \
+      --requests 8 --max-new 32 --max-batch 8
+
+The fused round has fixed shapes, so its device work does not depend
+on the weights.  Without ``--target`` the launcher trains (or loads
+from ``checkpoints/``) the small vocab-128 benchmark pair on the
+synthetic corpus first (CPU-scale demonstration of the full path).
+
+``build_server`` is the construction ``main`` uses — engine, then
+server — and ``chip_smoke.py`` drives the same function."""
 
 from __future__ import annotations
 
 import argparse
+import functools
 
 import jax
 import numpy as np
 
 
-def main():
+def make_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
+    ap.add_argument("--target", default=None,
+                    help="serve this repro.configs architecture at its "
+                         "published widths with seeded random weights "
+                         "(needs --drafter; default: the trained "
+                         "vocab-128 benchmark pair)")
+    ap.add_argument("--drafter", default=None,
+                    help="drafter architecture for --target (same "
+                         "vocabulary)")
+    ap.add_argument("--init-seed", type=int, default=0,
+                    help="seed of the random weights and prompts of "
+                         "--target/--drafter")
     ap.add_argument("--strategy", default="gls",
                     choices=("gls", "gls_strong", "specinfer", "spectr",
                              "single", "daliri"))
@@ -82,8 +108,8 @@ def main():
                     help="serving tensor parallelism (DESIGN.md §15): "
                          "run the fused round under shard_map on a "
                          "(tp,)-device 'model' mesh — weights output-dim "
-                         "sharded, KV arenas head-sharded, bit-identical "
-                         "to tp=1 (kv_fused only; CPU sessions need "
+                         "sharded, KV arenas head-sharded, all-gathers "
+                         "only (kv_fused only; CPU sessions need "
                          "XLA_FLAGS=--xla_force_host_platform_device_"
                          "count=N exported before launch)")
     ap.add_argument("--preempt-tokens", type=int, default=None,
@@ -136,7 +162,14 @@ def main():
                     help="circuit breaker: probe the most recent "
                          "degradation rung back after this many "
                          "consecutive clean rounds")
-    args = ap.parse_args()
+    return ap
+
+
+def parse_args(argv=None) -> argparse.Namespace:
+    ap = make_parser()
+    args = ap.parse_args(argv)
+    if (args.target is None) != (args.drafter is None):
+        ap.error("--target and --drafter go together")
     if args.cache_mode == "kv_fused" and args.backend == "legacy":
         ap.error("--cache-mode kv_fused needs a device verifier backend "
                  "(xla or pallas)")
@@ -151,11 +184,72 @@ def main():
     if (args.snapshot_every is not None or args.restore) \
             and args.journal_dir is None:
         ap.error("--snapshot-every / --restore need --journal-dir")
+    return args
 
-    import sys, os
+
+def init_served_params(cfg, key: jax.Array, mesh=None):
+    """Random weights for ``cfg``, made on the device by one jitted
+    ``init_params``.  With a serving-TP ``mesh`` every leaf is created
+    already in its ``serve_param_spec`` sharding, so no device ever
+    holds the whole model."""
+    from repro.models import init_params
+    init = functools.partial(init_params, cfg=cfg)
+    if mesh is None:
+        return jax.jit(init)(key)
+    from jax.sharding import NamedSharding
+    from repro.sharding.rules import serve_params_pspecs
+    specs = serve_params_pspecs(jax.eval_shape(init, key), mesh)
+    shardings = jax.tree.map(lambda sp: NamedSharding(mesh, sp), specs)
+    return jax.jit(init, out_shardings=shardings)(key)
+
+
+def random_pair(t_cfg, d_cfg, seed: int = 0, tp: int = 1):
+    """((target_params, t_cfg), (drafter_params, d_cfg)) with seeded
+    random weights, sharded over the first ``tp`` devices when tp > 1."""
+    mesh = None
+    if tp > 1:
+        from repro.launch.mesh import make_tp_mesh
+        mesh = make_tp_mesh(tp)
+    kt, kd = jax.random.split(jax.random.PRNGKey(seed))
+    return ((init_served_params(t_cfg, kt, mesh), t_cfg),
+            (init_served_params(d_cfg, kd, mesh), d_cfg))
+
+
+def random_prompts(n: int, vocab: int, seed: int = 0, min_len: int = 16,
+                   max_len: int = 128) -> list:
+    """``n`` seeded prompts of uniform random tokens in [1, vocab), with
+    lengths uniform in [min_len, max_len]."""
+    rng = np.random.default_rng(seed)
+    lens = rng.integers(min_len, max_len + 1, size=n)
+    return [rng.integers(1, vocab, size=int(m)).astype(np.int32)
+            for m in lens]
+
+
+def load_pair(args):
+    """The served (target, drafter) pair ``args`` names."""
+    if args.target is not None:
+        from repro.configs import get_config
+        return random_pair(get_config(args.target).replace(dtype="float32"),
+                           get_config(args.drafter).replace(dtype="float32"),
+                           seed=args.init_seed, tp=args.tp)
+    return _lm_pair().get_pair(steps=args.steps, log=print)
+
+
+def _lm_pair():
+    """The benchmark pair module (``benchmarks/`` sits beside ``src/``)."""
+    import os
+    import sys
     sys.path.insert(0, os.path.join(os.path.dirname(__file__),
                                     "..", "..", ".."))
-    from benchmarks.lm_pair import bench_prompts, get_pair
+    from benchmarks import lm_pair
+    return lm_pair
+
+
+def build_server(args, pair):
+    """Engine and server for parsed ``args`` (``parse_args``) over
+    ``pair`` (``load_pair``): the ``SpecDecServer`` →
+    ``CachedSpecDecEngine`` (or reference ``SpecDecEngine``) stack
+    ``main`` serves from."""
     from repro.serving import FaultPlan
     from repro.specdec import (
         CachedSpecDecEngine,
@@ -164,7 +258,7 @@ def main():
         SpecDecServer,
     )
 
-    target, drafter = get_pair(steps=args.steps, log=print)
+    target, drafter = pair
     k = 1 if args.strategy in ("single", "daliri") else args.drafts
     cfg = SpecDecConfig(num_drafts=k, draft_len=args.draft_len,
                         strategy=args.strategy, top_k=50,
@@ -198,17 +292,31 @@ def main():
                      rate_limit=args.rate_limit,
                      heal_after=args.heal_after)
     if args.restore:
-        server = SpecDecServer.restore(
+        return SpecDecServer.restore(
             args.journal_dir, eng, snapshot_every=args.snapshot_every,
             **server_kw)
+    return SpecDecServer(eng, journal_dir=args.journal_dir,
+                         snapshot_every=args.snapshot_every, **server_kw)
+
+
+def main(argv=None):
+    args = parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+    pair = load_pair(args)
+    server = build_server(args, pair)
+    eng = server.engine
+    k = eng.cfg.num_drafts
+    if args.restore:
         print(f"restored from {args.journal_dir}: "
               f"{len(server.queue)} resumable, {len(server.done)} done, "
               f"{len(server.failed)} failed")
     else:
-        server = SpecDecServer(eng, journal_dir=args.journal_dir,
-                               snapshot_every=args.snapshot_every,
-                               **server_kw)
-        for p in bench_prompts(args.requests):
+        prompts = (random_prompts(args.requests, pair[0][1].vocab_size,
+                                  args.init_seed)
+                   if args.target is not None
+                   else _lm_pair().bench_prompts(args.requests))
+        for p in prompts:
             server.submit(p, max_new=args.max_new)
 
     # Graceful shutdown (DESIGN.md §14): SIGTERM/SIGINT finish the

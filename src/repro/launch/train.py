@@ -33,12 +33,14 @@ def main():
 
     from repro.configs import get_config
     from repro.data import lm_dataset
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.launch.mesh import make_host_mesh, make_production_mesh
     from repro.models import init_params, param_count
     from repro.optim import adam_init
     from repro.sharding import batch_shardings, params_shardings
     from repro.train.loop import TrainConfig, make_train_step
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()

@@ -31,8 +31,7 @@ leaves — int8 ``k``/``v`` plus f32 per-KV-vector scales ``k_s``/``v_s``
 with a trailing singleton axis, so every arena op here (row gather /
 scatter on axis 1, time growth on axis 3) applies uniformly to all
 leaves.  The slots model calls quantize on write and dequantize inside
-the attention reads; ``write_prefill`` quantizes dense prefill caches on
-install.
+the attention reads, admission prefill included.
 
 Positions live in TWO places (DESIGN.md §8): the host mirror
 (``pool.pos``) is authoritative for admission/allocation and sizing
@@ -49,7 +48,6 @@ views never drift.
 
 from __future__ import annotations
 
-import functools
 import heapq
 from typing import Dict, Optional
 
@@ -65,11 +63,6 @@ from repro.models.registry import init_cache
 @jax.jit
 def _gather_rows(leaf, idx):
     return jnp.take(leaf, idx, axis=1)
-
-
-@functools.partial(jax.jit, static_argnames=("r0",))
-def _scatter_rows(leaf, rows, r0: int):
-    return jax.lax.dynamic_update_slice_in_dim(leaf, rows, r0, axis=1)
 
 
 @jax.jit
@@ -105,37 +98,45 @@ class CachePool:
         self._free = list(range(num_slots))
 
     def _init_arena(self, cfg: ModelConfig, buf_len: int) -> dict:
-        c = init_cache(cfg, self.num_slots * self.rows_per_slot, buf_len)
-        arena = {"k": c["k"], "v": c["v"]}   # positions live host-side
-        if self.quant:
-            # int8 leaves + per-KV-vector f32 scales (trailing-1 axis).
-            sshape = c["k"].shape[:-1] + (1,)
-            arena = {"k": jnp.zeros(c["k"].shape, jnp.int8),
-                     "v": jnp.zeros(c["v"].shape, jnp.int8),
-                     "k_s": jnp.zeros(sshape, jnp.float32),
-                     "v_s": jnp.zeros(sshape, jnp.float32)}
-        return self._place(arena)
+        c = jax.eval_shape(lambda: init_cache(
+            cfg, self.num_slots * self.rows_per_slot, buf_len))
+        kv = c["k"].shape
+        # Positions live host-side.  Quant arenas: int8 leaves plus
+        # per-KV-vector f32 scales (trailing-1 axis).
+        leaves = ({"k": (kv, jnp.int8), "v": (kv, jnp.int8),
+                   "k_s": (kv[:-1] + (1,), jnp.float32),
+                   "v_s": (kv[:-1] + (1,), jnp.float32)} if self.quant
+                  else {"k": (kv, c["k"].dtype), "v": (kv, c["v"].dtype)})
+        # Made in place on a serving-TP mesh: no device ever holds the
+        # whole arena.
+        return {kk: jnp.zeros(shape, dtype, device=self._sharding(shape))
+                for kk, (shape, dtype) in leaves.items()}
 
-    def _place(self, arena: dict) -> dict:
-        """Head-shard the arena over a serving-TP mesh (DESIGN.md §15):
-        axis 2 of every ``(layers, rows, kv_heads, T, head_dim)`` leaf
-        lands on the mesh's "model" axis, matching the column-sharded
-        wk/wv so each device owns exactly the KV its heads read/write.
-        Correctness never depends on placement (the fused round's
-        shard_map re-shards its inputs); pinning it at init/growth keeps
-        the steady state free of re-shard transfers.  No-op off-mesh."""
+    def _sharding(self, shape):
+        """Head-sharding of an arena leaf over a serving-TP mesh
+        (DESIGN.md §15): axis 2 of every ``(layers, rows, kv_heads, T,
+        head_dim)`` leaf lands on the mesh's "model" axis, matching the
+        column-sharded wk/wv so each device owns exactly the KV its heads
+        read/write.  Correctness never depends on placement (the fused
+        round's shard_map re-shards its inputs); pinning it at
+        init/growth keeps the steady state free of re-shard transfers.
+        None off-mesh."""
         if self.mesh is None:
-            return arena
+            return None
         from jax.sharding import NamedSharding
         from repro.sharding.rules import serve_cache_pspec
         tp = int(self.mesh.shape["model"])
-        out = {}
-        for kk, leaf in arena.items():
-            nd = leaf.ndim
-            spec = serve_cache_pspec(nd) if leaf.shape[2] % tp == 0 \
-                else serve_cache_pspec(0)
-            out[kk] = jax.device_put(leaf, NamedSharding(self.mesh, spec))
-        return out
+        spec = serve_cache_pspec(len(shape)) if shape[2] % tp == 0 \
+            else serve_cache_pspec(0)
+        return NamedSharding(self.mesh, spec)
+
+    def _place(self, arena: dict) -> dict:
+        """Pin every leaf of ``arena`` to its ``_sharding`` (no-op
+        off-mesh)."""
+        if self.mesh is None:
+            return arena
+        return {kk: jax.device_put(leaf, self._sharding(leaf.shape))
+                for kk, leaf in arena.items()}
 
     # -- slot lifecycle ----------------------------------------------------
     @property
@@ -182,27 +183,6 @@ class CachePool:
         self.buf_len = buf_len
 
     # -- cache content ops -------------------------------------------------
-    def write_prefill(self, name: str, slot: int, cache: dict,
-                      pos: int) -> None:
-        """Install a freshly prefilled ``(layers, rows_per_slot, ...)``
-        cache into ``slot``'s rows of arena ``name``; ``pos`` is the
-        number of prefilled tokens.  The prefill cache must have been
-        built at the pool's current ``buf_len``.  Quant pools accept a
-        dense {k, v} prefill cache and quantize it on install."""
-        arena = self.caches[name]
-        assert cache["k"].shape[3] == self.buf_len, \
-            "prefill cache buffer != pool buffer"
-        if self.quant and "k_s" not in cache:
-            from repro.serving.quant import quantize_kv
-            kq, ks = quantize_kv(cache["k"])
-            vq, vs = quantize_kv(cache["v"])
-            cache = {"k": kq, "v": vq, "k_s": ks, "v_s": vs}
-        r0 = slot * self.rows_per_slot
-        self.caches[name] = {kk: _scatter_rows(arena[kk], cache[kk], r0=r0)
-                             for kk in arena}
-        self.pos[slot] = pos
-        self._touch_pos(slot)
-
     def update(self, name: str, cache: dict) -> None:
         """Adopt the arena returned by a slots model call."""
         self.caches[name] = {kk: cache[kk] for kk in self.caches[name]}
@@ -342,7 +322,7 @@ class PagedCachePool(CachePool):
       * ``ensure_buf`` is a table WIDENING (append unmapped columns) —
         no storage copy, no whole-pool zero-pad regrowth;
       * storage is reserved per slot as its chain grows (``reserve``;
-        ``write_prefill`` reserves for the prompt, engines reserve
+        admission reserves for the prompt, engines reserve
         ``pos + L + 1`` before each round), so a free slot holds zero
         pages and a fixed ``num_pages`` budget can oversubscribe slots
         (more queued requests than physical capacity) — exhausting a
@@ -552,23 +532,6 @@ class PagedCachePool(CachePool):
         self.buf_len = buf_len
 
     # -- cache content ops -------------------------------------------------
-    def write_prefill(self, name: str, slot: int, cache: dict,
-                      pos: int) -> None:
-        assert cache["k"].shape[3] == self.buf_len, \
-            "prefill cache buffer != pool buffer"
-        if self.quant and "k_s" not in cache:
-            from repro.serving.quant import quantize_kv
-            kq, ks = quantize_kv(cache["k"])
-            vq, vs = quantize_kv(cache["v"])
-            cache = {"k": kq, "v": vq, "k_s": ks, "v_s": vs}
-        self.reserve(slot, pos)
-        r0 = slot * self.rows_per_slot
-        tbl = jnp.asarray(self.page_table[r0:r0 + self.rows_per_slot])
-        self.pages[name] = P.scatter_arena_jit(
-            self.pages[name], tbl, {kk: cache[kk] for kk in self.pages[name]})
-        self.pos[slot] = pos
-        self._touch_pos(slot)
-
     def update(self, name: str, pages: dict) -> None:
         """Adopt the pages returned by a ``*_slots_paged`` model call."""
         self.pages[name] = {kk: pages[kk] for kk in self.pages[name]}

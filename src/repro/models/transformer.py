@@ -246,14 +246,16 @@ def _rowwise_cache_write_masked(cache_k, cache_v, k, v, starts, write):
 
 def _block_prefill_slots(params_l, carry, cache_l, cfg: ModelConfig,
                          write, use_kernel: bool,
-                         interpret: Optional[bool]):
+                         interpret: Optional[bool],
+                         tp_axis: Optional[str] = None):
     """Prompt-chunk prefill with per-row start positions, straight into a
     cache arena (the batched admission step, DESIGN.md §9).  Identical
     attention structure to ``_block_verify_slots`` — causal over the
     row's own cache prefix plus the freshly written chunk — with two
     differences: rows outside the admission wave are write-masked, and
     ``use_kernel`` routes the chunk attention through the
-    ``kernels/flash_attention`` Pallas kernel."""
+    ``kernels/flash_attention`` Pallas kernel.  ``tp_axis`` as in
+    ``_block_decode_slots`` (serving TP, DESIGN.md §15)."""
     x, pos = carry  # x: (B, m, D); pos: (B,) per-row chunk start position
     p = params_l["attn"]
     hd = cfg.resolved_head_dim
@@ -275,9 +277,10 @@ def _block_prefill_slots(params_l, carry, cache_l, cfg: ModelConfig,
     out = L.attention(q, new_k, new_v, causal=True, q_offset=pos,
                       kv_len=pos + m, k_scale=k_scale, v_scale=v_scale,
                       use_kernel=use_kernel, interpret=interpret)
-    x = x + L.project_out(p, out)
+    x = x + L.project_out(p, out, tp_axis=tp_axis)
     x = x + L.swiglu(params_l["mlp"],
-                     L.rmsnorm(params_l["mlp_norm"], x, cfg.norm_eps))
+                     L.rmsnorm(params_l["mlp_norm"], x, cfg.norm_eps),
+                     tp_axis=tp_axis)
     return (x, pos), new_cache
 
 
@@ -285,7 +288,8 @@ def prefill_slots(params: dict, cfg: ModelConfig, tokens: jax.Array,
                   cache: dict, pos: jax.Array,
                   write: Optional[jax.Array] = None, *,
                   use_kernel: bool = False,
-                  interpret: Optional[bool] = None) -> dict:
+                  interpret: Optional[bool] = None,
+                  tp_axis: Optional[str] = None) -> dict:
     """Device-side admission prefill: tokens (B, m) prompt chunks land
     directly in their arena rows at per-row offsets ``pos`` (B,) —
     no temporary cache, no host scatter (DESIGN.md §9).  Returns the new
@@ -299,13 +303,15 @@ def prefill_slots(params: dict, cfg: ModelConfig, tokens: jax.Array,
     discarded.  Rows shorter than the chunk are padded by the caller;
     pad KV lands above the row's live prefix, where every consumer
     overwrites before attending (§9 safety argument).  Non-ring caches
-    only."""
+    only.  ``tp_axis`` runs the serving-TP sharded variant (pass a LOCAL
+    cfg and the local KV-head shard of the arena)."""
     assert not cfg.sliding_window, "prefill_slots: non-ring caches only"
     x = params["embed"][tokens]
     if write is None:
         write = jnp.ones((tokens.shape[0],), bool)
     fn = functools.partial(_block_prefill_slots, cfg=cfg, write=write,
-                           use_kernel=use_kernel, interpret=interpret)
+                           use_kernel=use_kernel, interpret=interpret,
+                           tp_axis=tp_axis)
     layer_cache = {kk: cache[kk] for kk in cache if kk != "pos"}
     (_, _), new_cache = scan_blocks(params["layers"], (x, pos), fn,
                                     cache=layer_cache)

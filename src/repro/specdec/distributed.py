@@ -4,8 +4,6 @@ This module is the glue between the fused speculative round
 (``engine_cached.build_round_core``) and a tensor-parallel mesh
 (``launch/mesh.make_tp_mesh``):
 
-  * ``shard_map_compat`` — one import site for the shard_map API across
-    JAX versions;
   * ``tp_round_specs`` — the fused round's in/out PartitionSpecs on a
     1-D ("model",) mesh: weights per ``sharding.rules.serve_param_spec``
     (every matmul output dim sharded), KV arenas head-sharded, and all
@@ -31,11 +29,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-
-try:  # jax >= 0.6 top-level API
-    shard_map_compat = jax.shard_map
-except AttributeError:  # older JAX: experimental namespace
-    from jax.experimental.shard_map import shard_map as shard_map_compat
 
 from repro.sharding.rules import serve_cache_pspec, serve_params_pspecs
 
@@ -71,16 +64,17 @@ def tp_round_specs(t_params, d_params, t_arena: dict, d_arena: dict, mesh,
 
 
 def tp_fused_round(round_core, mesh, in_specs, out_specs):
-    """shard_map-wrap a fused round body for the tensor-parallel mesh.
+    """shard_map-wrap a fused round body (or the engine's admission
+    prefill) for the tensor-parallel mesh.
 
-    ``check_rep=False``: the body runs replicated math (verification,
+    ``check_vma=False``: the body runs replicated math (verification,
     rollback indexing, RNG) on every device between all-gathers, which
     the static replication checker cannot always prove; correctness is
     instead enforced end-to-end by the bit-identity test battery
     (tests/test_sharded_round.py).
     """
-    return shard_map_compat(round_core, mesh=mesh, in_specs=in_specs,
-                            out_specs=out_specs, check_rep=False)
+    return jax.shard_map(round_core, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 # ---------------------------------------------------------------------------
@@ -131,7 +125,7 @@ def make_sharded_gls_verify(mesh, vocab_axis: str = "model"):
         return darg_g, targ_g
 
     spec_in = P(None, vocab_axis)
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         kernel, mesh=mesh,
         in_specs=(spec_in, spec_in, P(None)),
         out_specs=(P(None), P()))

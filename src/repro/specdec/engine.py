@@ -91,8 +91,9 @@ class SpecDecConfig:
     # Serving tensor parallelism (DESIGN.md §15): tp > 1 runs the cached
     # engine's fused round under shard_map on a 1-D ("model",) mesh
     # (launch/mesh.make_tp_mesh) — weights output-dim sharded per
-    # sharding/rules.serve_param_spec, KV arenas head-sharded — and is
-    # BIT-identical to tp=1 (all-gather-only collectives, no psum).
+    # sharding/rules.serve_param_spec, KV arenas head-sharded — with
+    # all-gather-only collectives (no psum); tokens equal tp=1 on the
+    # CPU backend, but not always on a TPU (DESIGN.md §15.1).
     # Requires num_heads/kv_heads/d_ff/padded_vocab/d_model of both
     # models divisible by tp; composes with the contiguous f32 arena
     # only (the paged gather and int8 arenas stay single-device).

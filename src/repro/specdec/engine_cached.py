@@ -63,10 +63,11 @@ observed prompt lengths.  ``round_with_admission`` additionally
 OVERLAPS admission with decoding: the fused round is dispatched first,
 the admission prefills are dispatched against its output arenas, and
 only then does the host block on the round's packed fetch — the
-admitted sessions join the live set next round.  Both admission paths
-produce bit-identical caches to per-request ``admit``
-(tests/test_admission.py); ``batched_admission=False`` keeps the
-per-request path for reference benchmarking.
+admitted sessions join the live set next round.  Per-request ``admit``
+is a one-request wave of the same program, so both admission paths
+write bit-identical caches (tests/test_admission.py); the scheduler's
+``admission="per_request"`` (one request per wave) is kept only as the
+TTFT baseline of the bursty-admission bench.
 """
 
 from __future__ import annotations
@@ -83,8 +84,6 @@ from repro.models import (
     PagedCachePool,
     decode_step_slots,
     decode_step_slots_paged,
-    init_cache,
-    prefill,
     prefill_slots,
     prefill_slots_paged,
     verify_step_slots,
@@ -338,7 +337,7 @@ class CachedSpecDecEngine:
     verification strategies route through the shared block verifier."""
 
     def __init__(self, target: tuple, drafter: tuple, cfg: SpecDecConfig,
-                 pool_slots: int = 1, batched_admission: bool = True,
+                 pool_slots: int = 1,
                  pool_pages: Optional[int] = None):
         self.t_params, self.t_cfg = target
         self.d_params, self.d_cfg = drafter
@@ -354,12 +353,10 @@ class CachedSpecDecEngine:
         self.vocab = self.t_cfg.vocab_size
         # Serving tensor parallelism (DESIGN.md §15): tp > 1 builds the
         # 1-D ("model",) mesh up front (fail fast on device count /
-        # divisibility) and runs the fused round under shard_map with
-        # LOCAL model configs.  Everything OUTSIDE the fused round —
-        # admission prefill, the host-driven kv path — keeps the full
-        # configs and replicated math: identical computation is bit-
-        # exact by definition, and the fused round's jit re-shards the
-        # arenas it consumes.
+        # divisibility) and runs the fused round and the admission
+        # prefill under shard_map with LOCAL model configs.  The host-
+        # driven kv path keeps the full configs; the scheduler serves
+        # tp > 1 through kv_fused only.
         self.mesh = None
         self._tp_axis = None
         self._t_cfg_local, self._d_cfg_local = self.t_cfg, self.d_cfg
@@ -414,40 +411,52 @@ class CachedSpecDecEngine:
         # slots whose view rows are newer than their pages.
         self._fused_view: Optional[dict] = None
         self._view_dirty: set = set()
-        self._t_prefill = jax.jit(
-            lambda p, b, c: prefill(p, self.t_cfg, b, c))
-        self._d_prefill = jax.jit(
-            lambda p, b, c: prefill(p, self.d_cfg, b, c))
         # Bucketed admission (DESIGN.md §9): stacked arena prefill, one
-        # compile per (model, bucket) — per-request ``admit`` compiles
-        # per observed prompt length instead.  The input arena is
-        # donated like the fused round's (§8 donation contract; CPU
-        # backends don't implement donation and would warn).
-        self.batched_admission = batched_admission
-        donate = (2,) if jax.default_backend() != "cpu" else ()
+        # compile per (model, bucket), shared by per-request ``admit``
+        # (a one-request wave).  The input arena is donated like the
+        # fused round's (§8 donation contract).
         self._slot_prefill = {
-            "target": jax.jit(
-                lambda p, t, c, pos, w: prefill_slots(
-                    p, self.t_cfg, t, c, pos, w,
-                    use_kernel=cfg.prefill_kernel,
-                    interpret=cfg.pallas_interpret),
-                donate_argnums=donate),
-            "drafter": jax.jit(
-                lambda p, t, c, pos, w: prefill_slots(
-                    p, self.d_cfg, t, c, pos, w,
-                    use_kernel=cfg.prefill_kernel,
-                    interpret=cfg.pallas_interpret),
-                donate_argnums=donate),
+            "target": self._build_slot_prefill(self.t_params,
+                                               self._t_cfg_local),
+            "drafter": self._build_slot_prefill(self.d_params,
+                                                self._d_cfg_local),
         }
         # Serving instrumentation (read by the scheduler / benchmarks).
         self.num_target_forwards = 0
         self.num_draft_forwards = 0
-        # Prefill model dispatches spent on admission: 2 per request on
-        # the per-request path, <= 2 x buckets per wave when batched.
+        # Prefill model dispatches spent on admission: 2 per chunk per
+        # request on the per-request path, <= 2 x buckets per chunk
+        # round per wave when batched.
         self.num_prefill_dispatches = 0
         # Device->host transfers spent materializing draft tokens (one
         # per draft step per round, shared across all live requests).
         self.num_draft_syncs = 0
+
+    def _build_slot_prefill(self, params, mcfg):
+        """Jitted admission prefill for one model.  Under serving TP it
+        runs sharded like the fused round (DESIGN.md §15): the weights
+        stay in their output-dim shards and the arena in its KV-head
+        shards, joined by exact all-gathers, so admission never gathers
+        a whole model onto one device."""
+        cfg = self.cfg
+
+        def fn(p, t, c, pos, w):
+            return prefill_slots(p, mcfg, t, c, pos, w,
+                                 use_kernel=cfg.prefill_kernel,
+                                 interpret=cfg.pallas_interpret,
+                                 tp_axis=self._tp_axis)
+
+        if self.mesh is not None:
+            from jax.sharding import PartitionSpec as P
+            from repro.sharding.rules import (serve_cache_pspec,
+                                              serve_params_pspecs)
+            from repro.specdec.distributed import tp_fused_round
+            kv = {"k": serve_cache_pspec(5), "v": serve_cache_pspec(5)}
+            fn = tp_fused_round(
+                fn, self.mesh,
+                (serve_params_pspecs(params, self.mesh), P(), kv, P(), P()),
+                kv)
+        return jax.jit(fn, donate_argnums=(2,))
 
     # -- pool / session lifecycle ------------------------------------------
     def _ensure_pool(self, buf_len: int) -> CachePool:
@@ -494,13 +503,12 @@ class CachedSpecDecEngine:
                     p, self.t_cfg, t, pg, tb, pos, buf_len=bl))
         else:
             mcfg = self.t_cfg if kind == "prefill_target" else self.d_cfg
-            donate = (2,) if jax.default_backend() != "cpu" else ()
             fn = jax.jit(
                 lambda p, t, pg, tb, pos, w: prefill_slots_paged(
                     p, mcfg, t, pg, tb, pos, w, buf_len=bl,
                     use_kernel=cfg.prefill_kernel,
                     interpret=cfg.pallas_interpret),
-                donate_argnums=donate)
+                donate_argnums=(2,))
         self._paged_jits[key] = fn
         return fn
 
@@ -755,30 +763,15 @@ class CachedSpecDecEngine:
         return self.pool.held_pages(self._sessions[uid].slot)
 
     def admit(self, uid: int, prompt: np.ndarray, buf_len: int) -> int:
-        """Per-request admission (the reference path): allocate a slot
-        and prefill both models with the prompt minus its last token
-        (which becomes the first pending token) via a temporary K-row
-        cache and a host-driven row scatter.  ``admit_batch`` is the
-        production path — bit-identical caches, bucketed dispatches."""
-        assert uid not in self._sessions
-        prompt = np.asarray(prompt, np.int32)
-        assert len(prompt) >= 1
-        pool = self._ensure_pool(buf_len)
-        slot = pool.alloc()
-        K = self.cfg.num_drafts
-        toks = jnp.broadcast_to(jnp.asarray(prompt[None, :-1]),
-                                (K, len(prompt) - 1))
-        for name, params, fn in (("target", self.t_params, self._t_prefill),
-                                 ("drafter", self.d_params, self._d_prefill)):
-            cache = init_cache(self.t_cfg if name == "target" else self.d_cfg,
-                               K, pool.buf_len)
-            _, cache = fn(params, {"tokens": toks}, cache)
-            pool.write_prefill(name, slot, cache, pos=len(prompt) - 1)
-            self.num_prefill_dispatches += 1
-        self._view_refresh({slot})
-        self._sessions[uid] = _Session(uid=uid, slot=slot,
-                                       pending=int(prompt[-1]))
-        return slot
+        """Per-request admission (the reference path): a one-request
+        wave of ``admit_batch``.  Both admission paths therefore run the
+        same arena-wide, bucketed ``prefill_slots`` program and write
+        bit-identical caches by construction — a separately shaped
+        prefill would leave that identity to the backend's matmul
+        choices (XLA:CPU routes small dots to a different kernel, whose
+        rounding differs).  Costs 2 dispatches per chunk per request."""
+        self.admit_batch([(uid, prompt)], buf_len)
+        return self._sessions[uid].slot
 
     def admit_batch(self, pairs, buf_len: int) -> None:
         """Bucketed batched admission (DESIGN.md §9): admit every
@@ -1067,10 +1060,8 @@ class CachedSpecDecEngine:
         round_core = build_round_core(
             cfg, self._t_cfg_local, self._d_cfg_local, self.vocab,
             self.pool.num_slots, tp_axis=self._tp_axis)
-        # Buffer donation (the §8 donation contract).  CPU backends do
-        # not implement donation and would warn on every dispatch, so
-        # only donate where it is real.
-        donate = (2, 3, 4) if jax.default_backend() != "cpu" else ()
+        # Buffer donation (the §8 donation contract), on every backend.
+        donate = (2, 3, 4)
         if cfg.tp == 1:
             return jax.jit(round_core, donate_argnums=donate)
         from repro.specdec.distributed import tp_fused_round, tp_round_specs
@@ -1188,14 +1179,10 @@ class CachedSpecDecEngine:
     # -- scheduler contract -------------------------------------------------
     def _admit_wave(self, pairs, buf_len: int,
                     admission: Optional[str] = None) -> None:
-        """Admit unseen sessions: one bucketed wave (``admit_batch``) or
-        per-request ``admit``.  ``admission`` overrides the engine's
-        ``batched_admission`` default per call (the scheduler passes its
-        own policy through rather than reconfiguring the engine)."""
-        if admission is None:
-            admission = "bucketed" if self.batched_admission \
-                else "per_request"
-        if admission == "bucketed":
+        """Admit unseen sessions: one bucketed wave (``admit_batch``, the
+        default) or, with ``admission="per_request"``, one ``admit``
+        wave per request (the scheduler passes its policy per call)."""
+        if admission in (None, "bucketed"):
             self.admit_batch(pairs, buf_len)
         else:
             for uid, prompt in pairs:

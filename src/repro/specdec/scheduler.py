@@ -43,9 +43,8 @@ every block's randomness to the admission policy.)
 Admission (``admission="bucketed"``, the default) drains the queue
 into the engine's bucketed batched-prefill waves; under kv_fused the
 wave's prefills are dispatched while the current round runs and the
-admitted requests join the live set next round.  ``per_request`` keeps
-the one-prefill-pair-per-request reference path (the TTFT baseline in
-the bursty-admission bench).
+admitted requests join the live set next round.  ``per_request`` admits
+one request per wave (the TTFT baseline in the bursty-admission bench).
 
 Buffer lengths grow monotonically to the largest live requirement
 (queued requests count from their admission round), so a request's
@@ -245,7 +244,7 @@ class SpecDecServer:
     ``admission`` picks the cached-engine prefill path: "bucketed"
     (default — batched bucketed waves straight into pool slots,
     overlapped with the running round under kv_fused, DESIGN.md §9) or
-    "per_request" (the reference path; also the TTFT baseline in the
+    "per_request" (one request per wave; the TTFT baseline in the
     bursty-admission bench).  The policy is passed through to the
     engine per call, never written onto it.
 
@@ -484,12 +483,16 @@ class SpecDecServer:
     # ---- admission / eviction policy ---------------------------------
 
     @staticmethod
-    def _order(req: Request):
+    def _order(req: Request, evictions: Optional[int] = None):
         """v2 queue order: priority first, then rotate evicted/preempted
         requests behind same-priority waiters, then earliest deadline
         first (EDF — deadline-free requests sort last, so deadline-free
-        traffic keeps the exact pre-§14 order), then submission order."""
-        return (-req.priority, req.evictions,
+        traffic keeps the exact pre-§14 order), then submission order.
+        ``evictions`` overrides the request's own count (the rank it
+        would take after one more displacement)."""
+        if evictions is None:
+            evictions = req.evictions
+        return (-req.priority, evictions,
                 req.t_deadline if req.t_deadline is not None else np.inf,
                 req.t_submit, req.uid)
 
@@ -619,8 +622,7 @@ class SpecDecServer:
             # immediately and pays a re-prefill for nothing (a high-
             # priority request is never preempted for low-priority
             # waiters).
-            displaced = (-req.priority, req.evictions + 1,
-                         req.t_submit, req.uid)
+            displaced = self._order(req, evictions=req.evictions + 1)
             if not any(self._order(q) < displaced for q in self.queue):
                 continue
             self._evict(req, now)

@@ -12,9 +12,7 @@ import pytest
 from repro.models import (
     CachePool,
     ModelConfig,
-    init_cache,
     init_params,
-    prefill,
     prefill_slots,
 )
 from repro.specdec import (
@@ -88,43 +86,45 @@ def test_max_bucket_is_pow2_within_buffer():
 
 
 def test_prefill_slots_matches_write_prefill(pair):
-    """The §9 device-write contract: a bucketed, padded, write-masked
-    prefill_slots wave leaves the slot rows bit-equal to the host
-    prefill + write_prefill scatter, and every other row untouched."""
-    tp, _ = pair
-    K, S, BUF = 2, 3, 40
-    prompt = _prompts((11,))[0]
-    n = len(prompt) - 1
+    """The §9 device-write contract: per-request ``admit`` (a
+    one-request wave) and a multi-request bucketed wave run the same
+    arena-wide, write-masked ``prefill_slots`` program, so every slot's
+    rows come out bit-equal whichever way it was admitted, and rows
+    outside the wave stay untouched.  (A separately shaped (K, n)
+    prefill scattered into the slot rows matches only while the
+    backend rounds a matmul row the same at every row count, which
+    XLA:CPU's small-dot kernel does not.)"""
+    tp, dp = pair
+    sd = SpecDecConfig(num_drafts=2, draft_len=2, strategy="gls", top_k=0)
+    S, BUF = 3, 40
+    prompts = _prompts((11, 20))            # buckets 16 and 32
 
-    ref_pool = CachePool({"m": TCFG}, num_slots=S, rows_per_slot=K,
-                         buf_len=BUF)
-    slot = ref_pool.alloc()
-    toks = jnp.broadcast_to(jnp.asarray(prompt[None, :-1]), (K, n))
-    cache = init_cache(TCFG, K, BUF)
-    _, cache = prefill(tp, TCFG, {"tokens": toks}, cache)
-    ref_pool.write_prefill("m", slot, cache, pos=n)
+    one = CachedSpecDecEngine((tp, TCFG), (dp, DCFG), sd, pool_slots=S)
+    for uid, p in enumerate(prompts):
+        one.admit(uid, p, BUF)
+    wave = CachedSpecDecEngine((tp, TCFG), (dp, DCFG), sd, pool_slots=S)
+    wave.admit_batch(list(enumerate(prompts)), BUF)
 
-    pool = CachePool({"m": TCFG}, num_slots=S, rows_per_slot=K, buf_len=BUF)
-    slot_b = pool.alloc()
-    assert slot_b == slot
-    rows = pool.rows_of(slot)
-    bucket = 16
-    tok = np.zeros((S * K, bucket), np.int32)
-    write = np.zeros((S * K,), bool)
-    tok[rows, :n] = prompt[:-1]
-    write[rows] = True
-    new = prefill_slots(tp, TCFG, jnp.asarray(tok), pool.caches["m"],
-                        jnp.zeros((S * K,), jnp.int32), jnp.asarray(write))
-    pool.update("m", new)
-    pool.set_pos(slot, n)
-
-    other = [r for r in range(S * K) if r not in rows]
-    for kk in ("k", "v"):
-        a = np.asarray(ref_pool.caches["m"][kk])
-        b = np.asarray(pool.caches["m"][kk])
-        np.testing.assert_array_equal(a[:, rows, :, :n], b[:, rows, :, :n])
-        np.testing.assert_array_equal(b[:, other], np.zeros_like(b[:, other]))
-    assert pool.pos[slot] == n
+    used = []
+    for uid, p in enumerate(prompts):
+        slot = one._sessions[uid].slot
+        assert wave._sessions[uid].slot == slot
+        assert one.pool.pos[slot] == wave.pool.pos[slot] == len(p) - 1
+        used.append((one.pool.rows_of(slot), len(p) - 1))
+    other = [r for r in range(S * sd.num_drafts)
+             if not any(r in rows for rows, _ in used)]
+    assert other
+    for name in ("target", "drafter"):
+        for kk in ("k", "v"):
+            a = np.asarray(one.pool.caches[name][kk])
+            b = np.asarray(wave.pool.caches[name][kk])
+            for rows, n in used:
+                np.testing.assert_array_equal(a[:, rows, :, :n],
+                                              b[:, rows, :, :n])
+            np.testing.assert_array_equal(b[:, other],
+                                          np.zeros_like(b[:, other]))
+            np.testing.assert_array_equal(a[:, other],
+                                          np.zeros_like(a[:, other]))
 
 
 def test_prefill_slots_kernel_route_allclose(pair):
@@ -210,8 +210,10 @@ def test_bucketed_admission_bit_identical(pair, strategy):
 
 
 def test_admission_policies_agree(pair):
-    """per_request and bucketed admission are interchangeable token-wise
-    (the §9 bit-identity contract between the two prefill writes)."""
+    """per_request (one-request waves) and bucketed (one multi-request
+    wave) admission are interchangeable token-wise: the same prefill
+    program, with other rows write-masked and prompts padded to their
+    bucket, writes the same caches (the §9 bit-identity contract)."""
     prompts = _prompts()
     a, _ = _serve(pair, "gls", "kv_fused", "per_request", prompts)
     b, _ = _serve(pair, "gls", "kv_fused", "bucketed", prompts)

@@ -1,11 +1,12 @@
 """Slot-based KV-cache arena: slot lifecycle, buffer growth, prefill
-scatter, and rollback-by-row-replication (DESIGN.md §7)."""
+into slot rows, and rollback-by-row-replication (DESIGN.md §7)."""
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.models import CachePool, ModelConfig, init_cache, init_params, prefill
+from repro.models import CachePool, ModelConfig, init_params, prefill_slots
 
 CFG = ModelConfig(name="p", family="dense", num_layers=2, d_model=32,
                   num_heads=4, num_kv_heads=2, head_dim=8, d_ff=64,
@@ -15,6 +16,21 @@ CFG = ModelConfig(name="p", family="dense", num_layers=2, d_model=32,
 def make_pool(slots=3, rows=2, buf=16):
     return CachePool({"m": CFG}, num_slots=slots, rows_per_slot=rows,
                      buf_len=buf)
+
+
+def prefill_slot(pool, params, slot, toks):
+    """Prefill ``toks`` (rows_per_slot, n) into ``slot``'s rows of arena
+    "m" the way admission does: one arena-wide ``prefill_slots`` with
+    every other row write-masked."""
+    rows = pool.rows_of(slot)
+    n_rows = pool.num_slots * pool.rows_per_slot
+    full = jnp.zeros((n_rows, toks.shape[1]), jnp.int32).at[rows].set(toks)
+    write = np.zeros((n_rows,), bool)
+    write[rows] = True
+    pool.update("m", prefill_slots(params, CFG, full, pool.caches["m"],
+                                   jnp.zeros((n_rows,), jnp.int32),
+                                   jnp.asarray(write)))
+    pool.set_pos(slot, toks.shape[1])
 
 
 def test_alloc_is_lowest_free_slot_first():
@@ -52,23 +68,19 @@ def test_write_prefill_and_rollback_replication():
     params = init_params(jax.random.PRNGKey(0), CFG)
     slot = pool.alloc()
     toks = jax.random.randint(jax.random.PRNGKey(1), (2, 5), 0, 32)
-    cache = init_cache(CFG, 2, pool.buf_len)
-    _, cache = prefill(params, CFG, {"tokens": toks}, cache)
-    pool.write_prefill("m", slot, cache, pos=5)
+    prefill_slot(pool, params, slot, toks)
     assert pool.pos[slot] == 5
-    arena = pool.caches["m"]
-    np.testing.assert_array_equal(np.asarray(arena["k"][:, 0:2]),
-                                  np.asarray(cache["k"]))
+    k = np.asarray(pool.caches["m"]["k"])
+    # The slot's rows hold the prompt's KV in positions 0..4 only; the
+    # other slot's rows are untouched.
+    assert np.abs(k[:, 0:2, :, :5]).max(axis=-1).all()
+    assert not k[:, 0:2, :, 5:].any() and not k[:, 2:4].any()
     # Replicate row 1 of slot 0 across the slot; slot 1 untouched.
-    before_other = np.asarray(arena["k"][:, 2:4])
     pool.rollback_rows(np.array([1, 1, 2, 3]))
-    arena = pool.caches["m"]
-    np.testing.assert_array_equal(np.asarray(arena["k"][:, 0]),
-                                  np.asarray(cache["k"][:, 1]))
-    np.testing.assert_array_equal(np.asarray(arena["k"][:, 1]),
-                                  np.asarray(cache["k"][:, 1]))
-    np.testing.assert_array_equal(np.asarray(arena["k"][:, 2:4]),
-                                  before_other)
+    got = np.asarray(pool.caches["m"]["k"])
+    np.testing.assert_array_equal(got[:, 0], k[:, 1])
+    np.testing.assert_array_equal(got[:, 1], k[:, 1])
+    np.testing.assert_array_equal(got[:, 2:4], k[:, 2:4])
 
 
 def test_ensure_buf_grows_and_preserves_content():
@@ -76,9 +88,7 @@ def test_ensure_buf_grows_and_preserves_content():
     params = init_params(jax.random.PRNGKey(0), CFG)
     slot = pool.alloc()
     toks = jax.random.randint(jax.random.PRNGKey(1), (2, 6), 0, 32)
-    cache = init_cache(CFG, 2, pool.buf_len)
-    _, cache = prefill(params, CFG, {"tokens": toks}, cache)
-    pool.write_prefill("m", slot, cache, pos=6)
+    prefill_slot(pool, params, slot, toks)
     old_k = np.asarray(pool.caches["m"]["k"])
     pool.ensure_buf(20)
     assert pool.buf_len == 20
@@ -90,12 +100,22 @@ def test_ensure_buf_grows_and_preserves_content():
     assert pool.buf_len == 20
 
 
-def test_prefill_buffer_mismatch_rejected():
-    pool = make_pool(slots=1, rows=2, buf=16)
-    slot = pool.alloc()
-    small = init_cache(CFG, 2, 8)
-    with pytest.raises(AssertionError):
-        pool.write_prefill("m", slot, small, pos=4)
+def test_prefill_of_one_slot_leaves_other_slots_bit_untouched():
+    """Admission into a second slot rewrites only its own rows: the
+    first slot's prefilled KV and position survive bit for bit."""
+    pool = make_pool(slots=2, rows=2, buf=16)
+    params = init_params(jax.random.PRNGKey(0), CFG)
+    a, b = pool.alloc(), pool.alloc()
+    prefill_slot(pool, params, a,
+                 jax.random.randint(jax.random.PRNGKey(1), (2, 5), 0, 32))
+    before = {kk: np.asarray(v) for kk, v in pool.caches["m"].items()}
+    prefill_slot(pool, params, b,
+                 jax.random.randint(jax.random.PRNGKey(2), (2, 7), 0, 32))
+    for kk, v in pool.caches["m"].items():
+        got = np.asarray(v)
+        np.testing.assert_array_equal(got[:, 0:2], before[kk][:, 0:2])
+        assert got[:, 2:4, :, :7].any() and not got[:, 2:4, :, 7:].any()
+    assert (pool.pos[a], pool.pos[b]) == (5, 7)
 
 
 def test_ring_caches_rejected():

@@ -15,10 +15,10 @@ import textwrap
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import AxisType
 from jax.sharding import PartitionSpec as P
 
 from repro.kernels.gls_race.ref import gls_race_ref
-from repro.launch.mesh import compat_make_mesh
 from repro.specdec.distributed import make_sharded_gls_verify, tp_round_specs
 
 
@@ -40,7 +40,7 @@ def _check(mesh):
 
 
 def test_sharded_verify_single_device():
-    mesh = compat_make_mesh((1,), ("model",))
+    mesh = jax.make_mesh((1,), ("model",), axis_types=(AxisType.Auto,))
     _check(mesh)
 
 
@@ -52,9 +52,9 @@ def test_sharded_verify_eight_devices_subprocess():
         sys.path.insert(0, "src")
         sys.path.insert(0, "tests")
         import jax
-        from repro.launch.mesh import compat_make_mesh
+        from jax.sharding import AxisType
         from test_distributed_verify import _check
-        mesh = compat_make_mesh((8,), ("model",))
+        mesh = jax.make_mesh((8,), ("model",), axis_types=(AxisType.Auto,))
         _check(mesh)
         print("SHARDED_OK")
     """)
@@ -83,7 +83,7 @@ def test_tp_round_specs_serving_layout():
                       num_heads=8, num_kv_heads=4, head_dim=16, d_ff=128,
                       vocab_size=64, dtype="float32")
     params = init_params(jax.random.PRNGKey(0), cfg)
-    mesh = compat_make_mesh((1,), ("model",))
+    mesh = jax.make_mesh((1,), ("model",), axis_types=(AxisType.Auto,))
     arena = {"k": jnp.zeros((2, 8, 4, 32, 16)),
              "v": jnp.zeros((2, 8, 4, 32, 16))}
     in_specs, out_specs = tp_round_specs(params, params, arena, arena, mesh)

@@ -22,9 +22,9 @@ from repro.models import (
     PagedCachePool,
     decode_step_slots,
     decode_step_slots_paged,
-    init_cache,
     init_params,
-    prefill,
+    prefill_slots,
+    prefill_slots_paged,
     verify_step_slots,
     verify_step_slots_paged,
 )
@@ -220,10 +220,15 @@ def _prefilled_pair(params, pos=5):
     sc, sp = cpool.alloc(), ppool.alloc()
     assert sc == sp == 0
     toks = jax.random.randint(jax.random.PRNGKey(1), (2, pos), 0, 32)
-    cache = init_cache(CFG, 2, 16)
-    _, cache = prefill(params, CFG, {"tokens": toks}, cache)
-    cpool.write_prefill("m", sc, cache, pos=pos)
-    ppool.write_prefill("m", sp, cache, pos=pos)
+    full = jnp.zeros((4, pos), jnp.int32).at[:2].set(toks)
+    start = jnp.zeros((4,), jnp.int32)
+    write = jnp.array([True, True, False, False])
+    cpool.update("m", prefill_slots(params, CFG, full, cpool.caches["m"],
+                                    start, write))
+    ppool.reserve(sp, pos)
+    ppool.update("m", prefill_slots_paged(
+        params, CFG, full, ppool.pages["m"], ppool.pt_device(), start,
+        write, buf_len=ppool.buf_len))
     cpool.set_pos(sc, pos)
     ppool.set_pos(sp, pos)
     return cpool, ppool, 0

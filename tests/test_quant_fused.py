@@ -1,5 +1,5 @@
 """Quantized serving tests (DESIGN.md §11): int8 KV cache-pool arena
-mechanics (quantize-on-install, bit-exact row replication and buffer
+mechanics (quantize-on-write, bit-exact row replication and buffer
 growth, per-vector dequant error bound), dequant-in-kernel attention
 reads, and the gate that matters — quantized-vs-bf16 ACCEPTANCE-RATE
 equivalence across all six verification strategies (quantization moves
@@ -13,7 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.models import CachePool, ModelConfig, init_cache, init_params
+from repro.models import CachePool, ModelConfig, init_params, prefill_slots
 from repro.serving.quant import dequantize_kv, quantize_kv
 from repro.specdec.block_verify import RACE_STRATEGIES, RS_STRATEGIES
 from repro.specdec.engine import SpecDecConfig
@@ -27,9 +27,27 @@ D_CFG = dataclasses.replace(T_CFG, name="d", d_model=32, d_ff=64,
                             num_heads=2, num_kv_heads=1)
 
 
-def _quant_pool(buf=16, slots=3, rows=2):
+def _quant_pool(buf=16, slots=3, rows=2, quant=True):
     return CachePool({"target": T_CFG, "drafter": D_CFG}, num_slots=slots,
-                     rows_per_slot=rows, buf_len=buf, quant=True)
+                     rows_per_slot=rows, buf_len=buf, quant=quant)
+
+
+def _prefill_target(pool, slot, n, seed=0):
+    """Prefill ``n`` seeded tokens into ``slot``'s target rows with the
+    admission program (arena-wide ``prefill_slots``, other rows
+    write-masked)."""
+    params = init_params(jax.random.PRNGKey(seed), T_CFG)
+    rows = pool.rows_of(slot)
+    n_rows = pool.num_slots * pool.rows_per_slot
+    toks = jax.random.randint(jax.random.PRNGKey(seed + 1),
+                              (len(rows), n), 0, T_CFG.vocab_size)
+    full = jnp.zeros((n_rows, n), jnp.int32).at[rows].set(toks)
+    write = np.zeros((n_rows,), bool)
+    write[rows] = True
+    pool.update("target", prefill_slots(
+        params, T_CFG, full, pool.caches["target"],
+        jnp.zeros((n_rows,), jnp.int32), jnp.asarray(write)))
+    pool.set_pos(slot, n)
 
 
 # ---------------------------------------------------------------------------
@@ -68,27 +86,28 @@ def test_quantize_kv_exact_for_representable_values():
 
 
 def test_quant_pool_arena_layout_and_prefill_install():
-    """Quant pools hold 4-leaf arenas; ``write_prefill`` quantizes a
-    dense prefill cache on install, bit-exact against quantize_kv."""
+    """Quant pools hold 4-leaf arenas; admission prefill quantizes on
+    write — layer 0's KV (which no quantized read precedes) is bit-exact
+    against ``quantize_kv`` of the same prefill into a dense arena, and
+    rows outside the slot stay zero."""
     pool = _quant_pool()
     for arena in pool.caches.values():
         assert set(arena) == {"k", "v", "k_s", "v_s"}
         assert arena["k"].dtype == jnp.int8
         assert arena["k_s"].shape == arena["k"].shape[:-1] + (1,)
-    slot = pool.alloc()
-    cache = init_cache(T_CFG, pool.rows_per_slot, pool.buf_len)
-    cache = {"k": jax.random.normal(jax.random.PRNGKey(2),
-                                    cache["k"].shape),
-             "v": jax.random.normal(jax.random.PRNGKey(3),
-                                    cache["v"].shape)}
-    pool.write_prefill("target", slot, cache, pos=5)
+    dense = _quant_pool(quant=False)
+    slot, dslot = pool.alloc(), dense.alloc()
+    _prefill_target(pool, slot, 5)
+    _prefill_target(dense, dslot, 5)
     rows = pool.rows_of(slot)
-    kq, ks = quantize_kv(cache["k"])
     arena = pool.caches["target"]
-    np.testing.assert_array_equal(np.asarray(arena["k"][:, rows]),
-                                  np.asarray(kq))
-    np.testing.assert_array_equal(np.asarray(arena["k_s"][:, rows]),
-                                  np.asarray(ks))
+    for kk in ("k", "v"):
+        q, s = quantize_kv(dense.caches["target"][kk][0, rows, :, :5])
+        np.testing.assert_array_equal(
+            np.asarray(arena[kk][0, rows, :, :5]), np.asarray(q))
+        np.testing.assert_array_equal(
+            np.asarray(arena[kk + "_s"][0, rows, :, :5]), np.asarray(s))
+        assert not np.asarray(arena[kk][:, rows[-1] + 1:]).any()
 
 
 def test_quant_pool_rollback_and_growth_bit_exact():
@@ -97,12 +116,7 @@ def test_quant_pool_rollback_and_growth_bit_exact():
     identically, bit for bit."""
     pool = _quant_pool(buf=8, slots=2, rows=2)
     slot = pool.alloc()
-    cache = init_cache(T_CFG, pool.rows_per_slot, pool.buf_len)
-    cache = {"k": jax.random.normal(jax.random.PRNGKey(4),
-                                    cache["k"].shape),
-             "v": jax.random.normal(jax.random.PRNGKey(5),
-                                    cache["v"].shape)}
-    pool.write_prefill("target", slot, cache, pos=3)
+    _prefill_target(pool, slot, 3, seed=4)
     before = {kk: np.asarray(v) for kk, v in pool.caches["target"].items()}
 
     # Replicate row 1 of the slot across both its rows.
