@@ -66,19 +66,6 @@ def check(cond, msg):
         raise SmokeFailure(msg)
 
 
-class CompileClock:
-    """Seconds JAX spends tracing, lowering and compiling, summed from
-    its ``/jax/core/compile/*`` duration events."""
-
-    def __init__(self, jax):
-        self.total = 0.0
-        jax.monitoring.register_event_duration_secs_listener(self._on)
-
-    def _on(self, event, duration, **_):
-        if event.startswith("/jax/core/compile/"):
-            self.total += duration
-
-
 def serve_once(serve, argv, pair, prompts, clock, jax):
     """Serve ``prompts`` through ``launch/serve.py``'s construction and
     check the served-path contracts; returns (tokens per request,
@@ -87,7 +74,7 @@ def serve_once(serve, argv, pair, prompts, clock, jax):
     server = serve.build_server(args, pair)
     for p in prompts:
         server.submit(p, max_new=args.max_new)
-    c0, t0 = clock.total, time.perf_counter()
+    c0, t0 = clock.compile_seconds(), time.perf_counter()
     done = server.run(jax.random.PRNGKey(0))
     wall = time.perf_counter() - t0
     m = server.metrics
@@ -103,7 +90,7 @@ def serve_once(serve, argv, pair, prompts, clock, jax):
     check(m.host_syncs == m.rounds,
           f"host_syncs={m.host_syncs} over {m.rounds} rounds")
     be = sum(r.block_efficiency for r in done) / len(done)
-    stats = {"compile_s": clock.total - c0, "wall_s": wall,
+    stats = {"compile_s": clock.compile_seconds() - c0, "wall_s": wall,
              "rounds": m.rounds, "tokens": m.total_tokens,
              "block_efficiency": be}
     return outs, server, stats
@@ -349,7 +336,9 @@ def main():
     from repro.launch import serve
     from repro.launch.compile_cache import enable_compile_cache
     print(f"compile cache: {enable_compile_cache()}")
-    clock = CompileClock(jax)
+    # Seconds JAX spends tracing, lowering and compiling, summed from
+    # its ``/jax/core/compile/*`` events by the serving spans' recorder.
+    from repro.serving import spans as clock
     if opts.tp == 1:
         one_chip(serve, clock, jax, jnp)
     else:

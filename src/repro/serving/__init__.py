@@ -1,7 +1,8 @@
 """Serving-side infrastructure: W8A8 int8 quantized verify path, the
 fault-tolerance layer (deterministic injection, round guards,
 watchdogs — DESIGN.md §13), and the durable-state / overload layer
-(write-ahead round journal, snapshots, admission control — §14)."""
+(write-ahead round journal, snapshots, admission control — §14), and
+the serving loop's spans and counters (``spans``, §16)."""
 
 from repro.serving.admission import (
     CircuitBreaker,

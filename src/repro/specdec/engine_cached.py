@@ -91,6 +91,7 @@ from repro.models import (
 )
 from repro.models import paged as paged_kv
 from repro.serving.guard import check_packed
+from repro.serving.spans import span
 from repro.specdec import verify as V
 from repro.specdec.block_verify import (
     RS_STRATEGIES,
@@ -139,6 +140,18 @@ def _bucket_plan(n: int, max_bucket: int) -> list:
     return chunks
 
 
+def _arena_bytes(arenas: dict) -> tuple:
+    """(bytes the arenas reserve, bytes per arena row and position),
+    from leaf shapes and dtypes alone.  Every leaf is laid out
+    ``(layers, rows, kv_heads, T, head_dim or 1)``."""
+    reserved = per_position = 0
+    for arena in arenas.values():
+        for leaf in arena.values():
+            reserved += leaf.nbytes
+            per_position += leaf.nbytes // (leaf.shape[1] * leaf.shape[3])
+    return reserved, per_position
+
+
 def _select_rollback_row(active: np.ndarray, num_accepted: int) -> int:
     """Surviving draft row for cache rollback.
 
@@ -170,6 +183,12 @@ def build_round_core(cfg: SpecDecConfig, t_cfg, d_cfg, vocab: int,
     Module-level so the engine (which jits it, optionally under
     shard_map) and ``launch/dryrun.py`` (which lowers it with abstract
     shapes for HLO/collective analysis) compile the SAME program.
+
+    Its stages run under ``jax.named_scope``s (``draft``, ``topk``,
+    ``target_verify``, ``block_verify``, ``rollback``, ``catch_up``),
+    which a profile shows in each op's name stack (DESIGN.md §16); the
+    HLO instruction names and the program's name ``jit_round_core`` do
+    not depend on them.
 
     ``tp_axis`` selects the tensor-parallel variant (DESIGN.md §15):
     the body then expects LOCAL model configs (num_heads / kv_heads
@@ -224,8 +243,9 @@ def build_round_core(cfg: SpecDecConfig, t_cfg, d_cfg, vocab: int,
                 d_params, d_cfg, cur, dc, row_pos + j,
                 use_kernel=cfg.decode_kernel,
                 interpret=cfg.pallas_interpret, tp_axis=tp_axis)
-            p_all = probs_from_logits(logits, cfg.temps[0], cfg.top_k,
-                                      N)
+            with jax.named_scope("topk"):
+                p_all = probs_from_logits(logits, cfg.temps[0], cfg.top_k,
+                                          N)
             tok = V.draft_token_from_uniforms(
                 log_u_j.reshape(rows, N), p_all)
             tok = jnp.where(live_row, tok, 0).astype(jnp.int32)
@@ -234,7 +254,8 @@ def build_round_core(cfg: SpecDecConfig, t_cfg, d_cfg, vocab: int,
 
         xs = (jnp.swapaxes(log_u[:, :L], 0, 1),
               jnp.arange(L, dtype=jnp.int32))
-        (_, d_kv1), ys = jax.lax.scan(dstep, (cur0, dict(d_kv)), xs)
+        with jax.named_scope("draft"):
+            (_, d_kv1), ys = jax.lax.scan(dstep, (cur0, dict(d_kv)), xs)
         toks = ys[0] if need_probs else ys            # (L, rows)
         d_tokens = toks.T.reshape(S, K, L)
         d_probs = (ys[1].reshape(L, S, K, N).transpose(1, 2, 0, 3)
@@ -242,16 +263,19 @@ def build_round_core(cfg: SpecDecConfig, t_cfg, d_cfg, vocab: int,
 
         # --- target: ONE stacked verify chunk over the arena ------
         chunk = jnp.concatenate([cur0, toks.T], axis=1)
-        t_logits, t_kv2 = verify_step_slots(
-            t_params, t_cfg, chunk, t_kv, row_pos, tp_axis=tp_axis)
-        q = probs_from_logits(t_logits, cfg.target_temp, cfg.top_k,
-                              N).reshape(S, K, L + 1, N)
+        with jax.named_scope("target_verify"):
+            t_logits, t_kv2 = verify_step_slots(
+                t_params, t_cfg, chunk, t_kv, row_pos, tp_axis=tp_axis)
+        with jax.named_scope("topk"):
+            q = probs_from_logits(t_logits, cfg.target_temp, cfg.top_k,
+                                  N).reshape(S, K, L + 1, N)
 
         # --- Algorithm 2, batched over slots ----------------------
-        res = block_verify_batched(
-            log_u, d_tokens, d_probs, q, strat_keys,
-            strategy=cfg.strategy, backend=cfg.verifier_backend,
-            interpret=cfg.pallas_interpret)
+        with jax.named_scope("block_verify"):
+            res = block_verify_batched(
+                log_u, d_tokens, d_probs, q, strat_keys,
+                strategy=cfg.strategy, backend=cfg.verifier_backend,
+                interpret=cfg.pallas_interpret)
         a = jnp.where(live, res.num_accepted, 0)
         # Surviving row: a == 0 -> row 0 (all rows agree on the
         # pending token); a > 0 -> first active row.  The a>0 ⇒
@@ -263,10 +287,11 @@ def build_round_core(cfg: SpecDecConfig, t_cfg, d_cfg, vocab: int,
         # --- arena rollback: in-program surviving-row gather ------
         surv = slot_of * K + k_star[slot_of]
         row_src = jnp.where(live_row, surv, row_ids)
-        t_kv2 = {kk: jnp.take(t_kv2[kk], row_src, axis=1)
-                 for kk in t_kv2}
-        d_kv2 = {kk: jnp.take(d_kv1[kk], row_src, axis=1)
-                 for kk in d_kv1}
+        with jax.named_scope("rollback"):
+            t_kv2 = {kk: jnp.take(t_kv2[kk], row_src, axis=1)
+                     for kk in t_kv2}
+            d_kv2 = {kk: jnp.take(d_kv1[kk], row_src, axis=1)
+                     for kk in d_kv1}
         new_pos = jnp.where(live, pos + 1 + a, pos)
 
         # --- residual drafter catch-up ----------------------------
@@ -283,11 +308,12 @@ def build_round_core(cfg: SpecDecConfig, t_cfg, d_cfg, vocab: int,
         extra_tok = jnp.where(full[slot_of], y_l[slot_of],
                               0).astype(jnp.int32)[:, None]
         extra_pos = jnp.where(full, pos + L, new_pos)
-        _, d_kv3 = decode_step_slots(
-            d_params, d_cfg, extra_tok, d_kv2,
-            jnp.repeat(extra_pos, K),
-            use_kernel=cfg.decode_kernel,
-            interpret=cfg.pallas_interpret, tp_axis=tp_axis)
+        with jax.named_scope("catch_up"):
+            _, d_kv3 = decode_step_slots(
+                d_params, d_cfg, extra_tok, d_kv2,
+                jnp.repeat(extra_pos, K),
+                use_kernel=cfg.decode_kernel,
+                interpret=cfg.pallas_interpret, tp_axis=tp_axis)
 
         packed = {"tokens": res.tokens, "accepted": a,
                   "active": res.active, "pos": new_pos}
@@ -806,7 +832,7 @@ class CachedSpecDecEngine:
                 # table bookkeeping only) so every chunk's scattered
                 # writes land in mapped pages.
                 pool.reserve(slot, len(prompt) - 1)
-            plans.append((slot, prompt[:-1],
+            plans.append((uid, slot, prompt[:-1],
                           _bucket_plan(len(prompt) - 1, max_bucket)))
         params = {"target": self.t_params, "drafter": self.d_params}
         # Paged + fused view live (§12): prefill straight INTO the view
@@ -816,46 +842,53 @@ class CachedSpecDecEngine:
         # Without a view (first wave, or the host-driven kv path) the
         # prefills scatter through the page table as before.
         use_view = paged and self._fused_view is not None
-        for c in range(max(len(p[2]) for p in plans)):
+        for c in range(max(len(p[3]) for p in plans)):
             groups = {}
-            for slot, toks, chunks in plans:
+            for uid, slot, toks, chunks in plans:
                 if c < len(chunks):
                     groups.setdefault(chunks[c][2], []).append(
-                        (slot, toks, chunks[c]))
+                        (uid, slot, toks, chunks[c]))
             for bucket in sorted(groups):
                 tok = np.zeros((rows_n, bucket), np.int32)
                 pos = np.zeros((rows_n,), np.int32)
                 write = np.zeros((rows_n,), bool)
-                for slot, toks, (off, ln, _) in groups[bucket]:
+                for _, slot, toks, (off, ln, _) in groups[bucket]:
                     rr = pool.rows_of(slot)
                     tok[rr, :ln] = toks[off:off + ln]
                     pos[rr] = off
                     write[rr] = True
                 tok_d, pos_d, write_d = (jnp.asarray(tok), jnp.asarray(pos),
                                          jnp.asarray(write))
+                # The dispatch computes rows_n x bucket arena cells; the
+                # prompt tokens it writes count once per request.
+                uids = [g[0] for g in groups[bucket]]
+                tokens = sum(g[3][1] for g in groups[bucket])
                 for name in ("target", "drafter"):
                     # Install each chunk's output arena immediately —
                     # the input buffer is donated, so pool.caches must
                     # never be left pointing at it (a mid-wave failure
                     # would otherwise corrupt the pool).
-                    if use_view:
-                        self._fused_view[name] = self._slot_prefill[name](
-                            params[name], tok_d, self._fused_view[name],
-                            pos_d, write_d)
-                    elif paged:
-                        pool.update(name, self._paged_jit(
-                            "prefill_" + name)(
-                                params[name], tok_d, pool.pages[name],
-                                pool.pt_device(), pos_d, write_d))
-                    else:
-                        pool.update(name, self._slot_prefill[name](
-                            params[name], tok_d, pool.caches[name], pos_d,
-                            write_d))
+                    with span("engine.prefill", model=name, bucket=bucket,
+                              rows=rows_n, tokens=tokens, uids=uids):
+                        if use_view:
+                            self._fused_view[name] = \
+                                self._slot_prefill[name](
+                                    params[name], tok_d,
+                                    self._fused_view[name], pos_d, write_d)
+                        elif paged:
+                            pool.update(name, self._paged_jit(
+                                "prefill_" + name)(
+                                    params[name], tok_d, pool.pages[name],
+                                    pool.pt_device(), pos_d, write_d))
+                        else:
+                            pool.update(name, self._slot_prefill[name](
+                                params[name], tok_d, pool.caches[name],
+                                pos_d, write_d))
                     self.num_prefill_dispatches += 1
-        for slot, toks, _ in plans:
+        for _, slot, toks, _ in plans:
             pool.set_pos(slot, len(toks))
         if use_view:
-            self._view_dirty.update(slot for slot, _, _ in plans)
+            self._view_dirty.update(p[1] for p in plans)
         elif paged:
             # The wave's prefills wrote PAGES behind an absent view;
             # nothing to pull (the next fused round's entry gather or
@@ -1087,93 +1120,107 @@ class CachedSpecDecEngine:
         prompts prefill while the round computes."""
         cfg, pool = self.cfg, self.pool
         K, L, S = cfg.num_drafts, cfg.draft_len, pool.num_slots
-        sessions = [self._sessions[u] for u in uids]
-        # Same loud non-ring overflow guard as the host-driven round.
-        hi = max(pool.pos[s.slot] for s in sessions) + L + 1
-        assert hi <= pool.buf_len, (
-            f"speculative block would write through position {hi - 1} but "
-            f"the cache arena holds {pool.buf_len}; pass a larger buf_len")
-        paged = isinstance(pool, PagedCachePool)
-        if paged:
-            # Host-side table bookkeeping before dispatch: each advancing
-            # slot's chain must cover the round's write horizon.
-            for sess in sessions:
-                pool.reserve(sess.slot, int(pool.pos[sess.slot]) + L + 1)
+        with span("engine.round", uids=list(uids)) as at:
+            sessions = [self._sessions[u] for u in uids]
+            # Same loud non-ring overflow guard as the host-driven round.
+            hi = max(pool.pos[s.slot] for s in sessions) + L + 1
+            assert hi <= pool.buf_len, (
+                f"speculative block would write through position {hi - 1} "
+                f"but the cache arena holds {pool.buf_len}; pass a larger "
+                "buf_len")
+            paged = isinstance(pool, PagedCachePool)
+            if paged:
+                # Host-side table bookkeeping before dispatch: each
+                # advancing slot's chain must cover the round's write
+                # horizon.
+                for sess in sessions:
+                    pool.reserve(sess.slot,
+                                 int(pool.pos[sess.slot]) + L + 1)
 
-        live = np.zeros(S, bool)
-        pending = np.zeros(S, np.int32)
-        # Free slots still need a syntactically valid key for the
-        # in-program randomness; their draws are masked garbage.
-        sub_rows = [jax.random.PRNGKey(0)] * S
-        for sess, sub in zip(sessions, subs):
-            live[sess.slot] = True
-            pending[sess.slot] = sess.pending
-            sub_rows[sess.slot] = sub
+            live = np.zeros(S, bool)
+            pending = np.zeros(S, np.int32)
+            # Free slots still need a syntactically valid key for the
+            # in-program randomness; their draws are masked garbage.
+            sub_rows = [jax.random.PRNGKey(0)] * S
+            for sess, sub in zip(sessions, subs):
+                live[sess.slot] = True
+                pending[sess.slot] = sess.pending
+                sub_rows[sess.slot] = sub
 
-        # The program closes over the view length (paged) and is keyed
-        # to pool geometry; rebuild when the buffer grows.  (The
-        # contiguous program re-traces on grown arena shapes anyway —
-        # rebuilding matches cost, old shapes never recur.)
-        if self._fused_round is None or self._fused_round_buf != pool.buf_len:
-            self._fused_round = self._build_fused_round()
-            self._fused_round_buf = pool.buf_len
-        if paged:
-            # First fused round (or first after a mode switch / buffer
-            # growth dropped the view): gather the working set ONCE.
-            # Every later round chains on the previous round's output
-            # arenas — the same donation flow as the contiguous path.
-            if self._fused_view is None:
-                pt = pool.pt_device()
-                self._fused_view = {
-                    name: paged_kv.gather_arena_jit(
-                        pool.pages[name], pt, buf_len=pool.buf_len)
-                    for name in ("target", "drafter")}
-                self._view_dirty.clear()
-            arenas = self._fused_view
-        else:
-            arenas = pool.caches
-        t_kv, d_kv, pos_dev, packed = self._fused_round(
-            self._t_verify_params, self.d_params,
-            arenas["target"], arenas["drafter"],
-            pool.pos_device(), jnp.asarray(pending), jnp.asarray(live),
-            jnp.stack(sub_rows))
-        self.num_draft_forwards += L + 1
-        self.num_target_forwards += 1
+            # The program closes over the view length (paged) and is
+            # keyed to pool geometry; rebuild when the buffer grows.  (The
+            # contiguous program re-traces on grown arena shapes anyway —
+            # rebuilding matches cost, old shapes never recur.)
+            if self._fused_round is None \
+                    or self._fused_round_buf != pool.buf_len:
+                self._fused_round = self._build_fused_round()
+                self._fused_round_buf = pool.buf_len
+            if paged:
+                # First fused round (or first after a mode switch /
+                # buffer growth dropped the view): gather the working set
+                # ONCE.  Every later round chains on the previous round's
+                # output arenas — the same donation flow as the contiguous
+                # path.
+                if self._fused_view is None:
+                    pt = pool.pt_device()
+                    self._fused_view = {
+                        name: paged_kv.gather_arena_jit(
+                            pool.pages[name], pt, buf_len=pool.buf_len)
+                        for name in ("target", "drafter")}
+                    self._view_dirty.clear()
+                arenas = self._fused_view
+            else:
+                arenas = pool.caches
+            # Reserved against used arena bytes, from shapes and dtypes
+            # (no device sync): the round sweeps every row and position.
+            reserved, per_position = _arena_bytes(arenas)
+            at["arena_bytes"] = reserved
+            at["live_bytes"] = per_position * K * int(
+                sum(pool.pos[s.slot] for s in sessions))
+            t_kv, d_kv, pos_dev, packed = self._fused_round(
+                self._t_verify_params, self.d_params,
+                arenas["target"], arenas["drafter"],
+                pool.pos_device(), jnp.asarray(pending), jnp.asarray(live),
+                jnp.stack(sub_rows))
+            self.num_draft_forwards += L + 1
+            self.num_target_forwards += 1
 
-        # Install the round's device outputs and use the in-flight gap
-        # to dispatch this wave's admission prefills (they consume the
-        # round's output arenas, so device execution stays ordered).
-        if paged:
-            self._fused_view = {"target": t_kv, "drafter": d_kv}
-            self._view_dirty.update(s.slot for s in sessions)
-            pool.adopt_pos_device(pos_dev)
-        else:
-            pool.adopt_round_device({"target": t_kv, "drafter": d_kv},
-                                    pos_dev)
+            # Install the round's device outputs; the in-flight gap then
+            # dispatches this wave's admission prefills (they consume the
+            # round's output arenas, so device execution stays ordered).
+            if paged:
+                self._fused_view = {"target": t_kv, "drafter": d_kv}
+                self._view_dirty.update(s.slot for s in sessions)
+                pool.adopt_pos_device(pos_dev)
+            else:
+                pool.adopt_round_device({"target": t_kv, "drafter": d_kv},
+                                        pos_dev)
         if admits:
             self.admit_batch(admits, pool.buf_len)
 
-        host = jax.device_get(packed)          # the round's ONE transfer
-        pool.refresh_pos_host(host["pos"], [s.slot for s in sessions])
-        # Guard the raw fetch (DESIGN.md §13): token range/finiteness,
-        # accepted bounds, and the rollback invariant — a NaN-poisoned
-        # logit row makes the race argmax emit garbage ids, and this is
-        # the last point before that garbage becomes session state.
-        check_packed(host, [(s.uid, s.slot) for s in sessions],
-                     vocab=self.vocab, draft_len=L)
-        outs = []
-        for i, sess in enumerate(sessions):
-            s = sess.slot
-            acc = int(host["accepted"][s])
-            active = np.asarray(host["active"][s])
-            toks = [int(t) for t in host["tokens"][s][:acc + 1]]
-            sess.pending = toks[-1]
-            # The packed fetch is one transfer for the WHOLE round;
-            # attribute it to the round's first outcome so aggregate
-            # accounting reads host_syncs == rounds (§7.3).
-            outs.append(BlockOutcome(new_tokens=toks, accepted=acc,
-                                     verify_syncs=1 if i == 0 else 0,
-                                     active=active))
+        with span("engine.fetch"):
+            host = jax.device_get(packed)      # the round's ONE transfer
+        with span("engine.check"):
+            pool.refresh_pos_host(host["pos"], [s.slot for s in sessions])
+            # Guard the raw fetch (DESIGN.md §13): token range/finiteness,
+            # accepted bounds, and the rollback invariant — a NaN-poisoned
+            # logit row makes the race argmax emit garbage ids, and this
+            # is the last point before that garbage becomes session state.
+            check_packed(host, [(s.uid, s.slot) for s in sessions],
+                         vocab=self.vocab, draft_len=L)
+            outs = []
+            for i, sess in enumerate(sessions):
+                s = sess.slot
+                acc = int(host["accepted"][s])
+                active = np.asarray(host["active"][s])
+                toks = [int(t) for t in host["tokens"][s][:acc + 1]]
+                sess.pending = toks[-1]
+                # The packed fetch is one transfer for the WHOLE round;
+                # attribute it to the round's first outcome so aggregate
+                # accounting reads host_syncs == rounds (§7.3).
+                outs.append(BlockOutcome(new_tokens=toks, accepted=acc,
+                                         verify_syncs=1 if i == 0 else 0,
+                                         active=active))
         return outs
 
     # -- scheduler contract -------------------------------------------------

@@ -95,6 +95,7 @@ from repro.serving.snapshot import (
     unpack_request,
     write_snapshot,
 )
+from repro.serving.spans import span
 from repro.specdec.engine import SpecDecConfig, SpecDecEngine
 
 
@@ -696,47 +697,55 @@ class SpecDecServer:
         the same sheet the discarded round drew."""
         t0 = time.perf_counter()
         try:
-            newly = self._admit()
-            if not self.live:
-                return []
-            self._buf_len = max([self._buf_len]
-                                + [self._required_buf(r)
-                                   for r in self.live])
-            overlap = (self.cache_mode == "kv_fused"
-                       and self.admission == "bucketed")
-            new_ids = {id(r) for r in newly}
-            advancing = [r for r in self.live if id(r) not in new_ids] \
-                if overlap else self.live
-            # Nested folds: a flat uid * C + blocks encoding collides
-            # across requests once blocks reaches C (module docstring).
-            subs = [jax.random.fold_in(jax.random.fold_in(key, r.uid),
-                                       r.blocks)
-                    for r in advancing]
-            fw0 = self.engine.num_target_forwards
-            ds0 = getattr(self.engine, "num_draft_syncs", 0)
-            try:
-                outs = self._dispatch(subs, advancing, newly, overlap)
-            except Exception as fault:
-                if not (self.guarded and self._is_recoverable(fault)):
-                    raise
-                self._recover(fault, newly)
-                return []
-            if advancing:
-                self.metrics.rounds += 1
-            self.metrics.target_forwards += \
-                self.engine.num_target_forwards - fw0
-            self.metrics.draft_syncs += (
-                getattr(self.engine, "num_draft_syncs", 0) - ds0)
-            finished = self._commit(advancing, outs)
-            self._consec_faults = 0
-            # Healing probe (DESIGN.md §14): after enough consecutive
-            # clean committed rounds the circuit breaker half-opens and
-            # the server climbs the most recent ladder rung back.
-            if (self._breaker is not None and advancing
-                    and self._ladder_taken
-                    and self._breaker.record_commit()):
-                self._heal()
-            return finished
+            with span("serve.step"):
+                with span("serve.admit") as at:
+                    newly = self._admit()
+                    at["uids"] = [r.uid for r in newly]
+                if not self.live:
+                    return []
+                self._buf_len = max([self._buf_len]
+                                    + [self._required_buf(r)
+                                       for r in self.live])
+                overlap = (self.cache_mode == "kv_fused"
+                           and self.admission == "bucketed")
+                new_ids = {id(r) for r in newly}
+                advancing = [r for r in self.live if id(r) not in new_ids] \
+                    if overlap else self.live
+                # Nested folds: a flat uid * C + blocks encoding collides
+                # across requests once blocks reaches C (module docstring).
+                with span("serve.keys", n=len(advancing)):
+                    subs = [jax.random.fold_in(
+                        jax.random.fold_in(key, r.uid), r.blocks)
+                        for r in advancing]
+                fw0 = self.engine.num_target_forwards
+                ds0 = getattr(self.engine, "num_draft_syncs", 0)
+                try:
+                    with span("serve.dispatch"):
+                        outs = self._dispatch(subs, advancing, newly,
+                                              overlap)
+                except Exception as fault:
+                    if not (self.guarded and self._is_recoverable(fault)):
+                        raise
+                    with span("serve.recover"):
+                        self._recover(fault, newly)
+                    return []
+                if advancing:
+                    self.metrics.rounds += 1
+                self.metrics.target_forwards += \
+                    self.engine.num_target_forwards - fw0
+                self.metrics.draft_syncs += (
+                    getattr(self.engine, "num_draft_syncs", 0) - ds0)
+                with span("serve.commit"):
+                    finished = self._commit(advancing, outs)
+                self._consec_faults = 0
+                # Healing probe (DESIGN.md §14): after enough consecutive
+                # clean committed rounds the circuit breaker half-opens
+                # and the server climbs the most recent ladder rung back.
+                if (self._breaker is not None and advancing
+                        and self._ladder_taken
+                        and self._breaker.record_commit()):
+                    self._heal()
+                return finished
         finally:
             self.metrics.wall_s += time.perf_counter() - t0
 
@@ -1142,8 +1151,9 @@ class SpecDecServer:
         """Record a permanent per-request failure (shed / deadline /
         quarantine / callback) so replay reproduces the failed set."""
         if self._journal is not None:
-            self._journal.append({"t": "fail", "uid": req.uid,
-                                  "kind": kind, "error": req.error})
+            with span("serve.journal"):
+                self._journal.append({"t": "fail", "uid": req.uid,
+                                      "kind": kind, "error": req.error})
 
     def _journal_commit(self, advancing, emits) -> None:
         """One fsync'd record per committed round: absolute (blocks,
@@ -1163,7 +1173,8 @@ class SpecDecServer:
         }
         if hasattr(self.engine, "residency"):
             rec["res"] = self.engine.residency()
-        self._journal.append(rec)
+        with span("serve.journal"):
+            self._journal.append(rec)
 
     def snapshot(self) -> str:
         """Write a point-in-time snapshot NOW (also runs on the
@@ -1181,7 +1192,8 @@ class SpecDecServer:
             return
         self._rounds_since_snap += 1
         if self._rounds_since_snap >= self.snapshot_every:
-            self.snapshot()
+            with span("serve.snapshot"):
+                self.snapshot()
 
     def begin_drain(self) -> None:
         """Stop accepting submits — every subsequent ``submit`` raises

@@ -14,12 +14,16 @@ described the tests skip.
 """
 
 import functools
+import os
+import re
+import sys
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 B, H, HKV, T, D = 8, 15, 5, 2048, 64
 VOCAB = 49_152
 
@@ -148,7 +152,16 @@ def test_fused_round_compiles(one_chip):
     round_core = build_round_core(sd, t_cfg, d_cfg, t_cfg.vocab_size, slots)
     compiled = jax.jit(round_core, donate_argnums=(2, 3, 4)).lower(
         *args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo
+    # The chip benchmark finds the race kernel and the top-k sorts by
+    # their HLO instruction text, which the round's named scopes leave
+    # alone (DESIGN.md §16).
+    sys.path.insert(0, ROOT)
+    from chipbench.programs import RACE, SORT
+    ops = [line.strip().removeprefix("ROOT ") for line in hlo.splitlines()]
+    assert any(re.search(RACE, op) for op in ops)
+    assert any(re.search(SORT, op) for op in ops)
 
 
 def test_ssd_chunk_compiles(one_chip):
