@@ -12,17 +12,22 @@ the slot-aware multi-token ``verify_step_slots`` (DESIGN.md §7):
              request's (pending token + L drafts)
              fused block verification on shared uniforms (Alg. 2,
              block_verify.py — same dispatcher as the reference engine)
-             cache rollback = arena-wide surviving-row replication
+             cache rollback = surviving-row replication (the whole
+             row here; only the L+1 written positions in the fused
+             round below)
 
 Cache rollback correctness: row k* survived steps 1..a, so its cache
 slots [pos, pos+a] hold exactly [pending, Y_1..Y_a]; replicating row k*
 into all of the slot's rows and rewinding pos to pos+a+1 leaves every
-row's cache equal to the accepted prefix.  The bonus/residual token
-Y_{a+1} becomes the next block's pending token (its KV enters the cache
-when scored).  Row selection contract: when a == 0 every row's slot[pos]
-(the shared pending token) is identical, so row 0 is valid; when a > 0
-at least one row MUST be active (``_select_rollback_row`` asserts this
-invariant instead of letting ``argmax`` silently pick a dead row 0).
+row's cache equal to the accepted prefix.  The fused round copies only
+the window [pos, pos+L] the round wrote: every row of a live slot
+already agrees on [0, pos) (``build_round_core``).  The bonus/residual
+token Y_{a+1} becomes the next block's pending token (its KV enters the
+cache when scored).  Row selection contract: when a == 0 every row's
+slot[pos] (the shared pending token) is identical, so row 0 is valid;
+when a > 0 at least one row MUST be active (``_select_rollback_row``
+asserts this invariant instead of letting ``argmax`` silently pick a
+dead row 0).
 
 Host-sync accounting (DESIGN.md §7.3): ``GenerationStats.host_syncs``
 counts every device->host transfer the verification path performs.  The
@@ -45,7 +50,7 @@ slot — correct, but it re-prefills per block.
 device program (DESIGN.md §8): the L-step drafter sweep runs as a
 ``lax.scan`` with drafted tokens staying device-resident, the stacked
 verify, batched Algorithm-2 verification, surviving-row selection,
-arena-wide rollback, and the residual drafter catch-up all execute in
+windowed rollback, and the residual drafter catch-up all execute in
 the same dispatch with donated cache buffers, and the only
 device->host transfer per round is the packed result fetch
 (``draft_syncs == 0``, one ``host_sync`` per round).  Token streams are
@@ -173,6 +178,35 @@ def _select_rollback_row(active: np.ndarray, num_accepted: int) -> int:
     return int(hits[0])
 
 
+def _replicate_windows(arenas, row_src, starts, m: int):
+    """Copy row ``row_src[r]``'s positions ``[starts[r], starts[r] + m)``
+    into row r at the same positions, in every leaf of every arena
+    (each laid out ``(layers, rows, kv_heads, T, head_dim or 1)``).  A
+    row that is its own source rewrites its own bytes, so it stays
+    bit-for-bit as it was, even where its window clamps at the buffer's
+    end (the read clamps the same way).  A source row is its own
+    source, so the order of the rows does not matter.  One loop over
+    the rows moves (layers, 1, kv_heads, m, ·) per leaf with
+    ``dynamic_slice`` / ``dynamic_update_slice`` in place: neither
+    prefers a layout, so the arenas keep theirs (a gather and scatter
+    indexed on the row and time axes make the TPU compiler re-lay
+    whole arenas out around them)."""
+    leaves, tree = jax.tree.flatten(arenas)
+
+    def row(r, leaves):
+        out = []
+        for leaf in leaves:
+            ly, _, h, _, d = leaf.shape
+            win = jax.lax.dynamic_slice(leaf, (0, row_src[r], 0, starts[r], 0),
+                                        (ly, 1, h, m, d))
+            out.append(jax.lax.dynamic_update_slice(
+                leaf, win, (0, r, 0, starts[r], 0)))
+        return out
+
+    rows = leaves[0].shape[1]
+    return jax.tree.unflatten(tree, jax.lax.fori_loop(0, rows, row, leaves))
+
+
 def build_round_core(cfg: SpecDecConfig, t_cfg, d_cfg, vocab: int,
                      num_slots: int, tp_axis: Optional[str] = None):
     """The fused speculative round as a pure function (DESIGN.md §8):
@@ -183,6 +217,16 @@ def build_round_core(cfg: SpecDecConfig, t_cfg, d_cfg, vocab: int,
     Module-level so the engine (which jits it, optionally under
     shard_map) and ``launch/dryrun.py`` (which lowers it with abstract
     shapes for HLO/collective analysis) compile the SAME program.
+
+    Rollback copies only the L+1 positions ``[pos, pos+L]`` the round
+    wrote, from the slot's surviving row into its other rows.  That is
+    exact because of the window invariant: at round start every row of
+    a live slot holds the same content on ``[0, pos)`` — admission
+    prefills all K rows alike, and each round's window copy and
+    catch-up keep them equal through ``pos + L``, past the next round's
+    start.  Positions past ``pos + L`` keep each row's own garbage,
+    beyond every row's ``kv_len``.  Rows of slots not in ``live`` are
+    left untouched.
 
     Its stages run under ``jax.named_scope``s (``draft``, ``topk``,
     ``target_verify``, ``block_verify``, ``rollback``, ``catch_up``),
@@ -284,14 +328,15 @@ def build_round_core(cfg: SpecDecConfig, t_cfg, d_cfg, vocab: int,
         k_star = jnp.where(
             a > 0, jnp.argmax(res.active, axis=1).astype(jnp.int32), 0)
 
-        # --- arena rollback: in-program surviving-row gather ------
+        # --- arena rollback: the surviving row's written window ---
+        # Live rows take [pos, pos+L] from their slot's row k*; the
+        # engine asserts pos + L + 1 <= buf_len for them, so no live
+        # window clamps.  Rows of other slots are their own source.
         surv = slot_of * K + k_star[slot_of]
         row_src = jnp.where(live_row, surv, row_ids)
         with jax.named_scope("rollback"):
-            t_kv2 = {kk: jnp.take(t_kv2[kk], row_src, axis=1)
-                     for kk in t_kv2}
-            d_kv2 = {kk: jnp.take(d_kv1[kk], row_src, axis=1)
-                     for kk in d_kv1}
+            t_kv2, d_kv2 = _replicate_windows((t_kv2, d_kv1), row_src,
+                                              row_pos, L + 1)
         new_pos = jnp.where(live, pos + 1 + a, pos)
 
         # --- residual drafter catch-up ----------------------------
@@ -1177,6 +1222,8 @@ class CachedSpecDecEngine:
             at["arena_bytes"] = reserved
             at["live_bytes"] = per_position * K * int(
                 sum(pool.pos[s.slot] for s in sessions))
+            # The rollback's windowed copy: L+1 positions of every row.
+            at["rollback_bytes"] = per_position * S * K * (L + 1)
             t_kv, d_kv, pos_dev, packed = self._fused_round(
                 self._t_verify_params, self.d_params,
                 arenas["target"], arenas["drafter"],

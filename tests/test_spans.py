@@ -213,6 +213,21 @@ def test_wave_arena_bytes(wave):
     assert 0 < rnd["live_bytes"] <= rnd["arena_bytes"]
 
 
+def test_wave_rollback_bytes(wave):
+    """The round's rollback moves the L+1-position window of every arena
+    row: bytes per row and position x rows x (L+1)."""
+    server, recs, _, _ = wave
+    eng = server.engine
+    (rnd,) = [r.attrs for r in recs if r.name == "engine.round"]
+    leaves = [leaf for a in eng.pool.caches.values() for leaf in a.values()]
+    per_position = sum(leaf.nbytes // (leaf.shape[1] * leaf.shape[3])
+                       for leaf in leaves)
+    rows = eng.pool.num_slots * eng.cfg.num_drafts
+    assert rnd["rollback_bytes"] == \
+        per_position * rows * (eng.cfg.draft_len + 1)
+    assert 0 < rnd["rollback_bytes"] < rnd["arena_bytes"]
+
+
 def test_program_names_match_the_benchmark(wave):
     """The fused round and the slot prefill compile to the module names
     ``chipbench/programs.py`` finds them by on the chip; a rename would

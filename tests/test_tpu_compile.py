@@ -118,11 +118,34 @@ def test_qdot_int8_compiles(one_chip, monkeypatch):
     assert "s8[" in hlo and "s32[" in hlo
 
 
+# Arena-shaped copies (``copy``, or an async ``copy-start``/``copy-done``
+# pair) in the compiled fused round below before the rollback copied
+# only its window: layout changes around the layer scans.  The windowed
+# rollback must add none.
+ROUND_ARENA_COPIES = 10
+
+
+def _arena_ops(hlo: str, shapes) -> list:
+    """(op, line) of every instruction whose result, or a tuple result's
+    first element, has one of ``shapes`` (dims joined by commas)."""
+    out = []
+    for line in hlo.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%\S+ = \(?\w+\[([\d,]*)\]\S* ([\w-]+)\(",
+                     line)
+        if m and m.group(1) in shapes:
+            out.append((m.group(2), line))
+    return out
+
+
 def test_fused_round_compiles(one_chip):
     """The kv_fused round core — drafter sweep, stacked verify, the
     Pallas race verifier, rollback — at SmolLM-360M / SmolLM-135M widths
     cut to 2 layers each, 8 slots x K=8 x L=4, lowered with abstract
-    shapes as ``launch/dryrun.py`` lowers the sharded round."""
+    shapes as ``launch/dryrun.py`` lowers the sharded round.  The
+    rollback copies only each row's window: no gather with a whole
+    arena's result, one in-place dynamic-update-slice per arena leaf,
+    and no arena-shaped copy beyond those the whole-row gather's round
+    had."""
     from repro.configs import get_config
     from repro.models import init_cache, init_params
     from repro.specdec.engine import SpecDecConfig
@@ -162,6 +185,16 @@ def test_fused_round_compiles(one_chip):
     ops = [line.strip().removeprefix("ROOT ") for line in hlo.splitlines()]
     assert any(re.search(RACE, op) for op in ops)
     assert any(re.search(SORT, op) for op in ops)
+
+    arenas = (args[2], args[3])
+    shapes = {",".join(map(str, a["k"].shape)) for a in arenas}
+    arena_ops = _arena_ops(hlo, shapes)
+    assert not [line for op, line in arena_ops if op == "gather"]
+    writes = [op for op, line in arena_ops if "/rollback/" in line
+              and op not in ("get-tuple-element", "bitcast")]
+    assert writes == ["dynamic-update-slice"] * sum(len(a) for a in arenas)
+    copies = [line for op, line in arena_ops if op in ("copy", "copy-done")]
+    assert len(copies) <= ROUND_ARENA_COPIES, copies
 
 
 def test_ssd_chunk_compiles(one_chip):
